@@ -5,7 +5,7 @@ contribution); these kernels are the perf-critical layers of the models the
 scheduler serves. Each kernel ships as a trio:
 
     <name>/kernel.py  — pl.pallas_call + explicit BlockSpec VMEM tiling
-    <name>/ops.py     — jit'd public wrapper (interpret=True on CPU)
+    <name>/ops.py     — jit'd public wrapper (pass interpret=True off TPU)
     <name>/ref.py     — pure-jnp oracle used by the allclose tests
 """
 from repro.kernels.decode_attention.ops import decode_attention  # noqa: F401

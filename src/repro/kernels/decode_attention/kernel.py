@@ -42,13 +42,13 @@ def _decode_kernel(pos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
         preferred_element_type=jnp.float32) * scale        # [G, bk]
 
     pos = pos_ref[0]                                       # scalar in SMEM
-    kp = kpos_ref[...]                                     # [bk] slot pos
+    kp = kpos_ref[...]                                     # [1, bk] slot pos
     valid = (kp >= 0) & (kp <= pos)
     if window is not None:
         valid &= pos - kp < window
     if chunk is not None:
         valid &= (pos // chunk) == (kp // chunk)
-    logits = jnp.where(valid[None, :], logits, NEG)
+    logits = jnp.where(valid, logits, NEG)
 
     m_prev = m_scr[:, :1]
     l_prev = l_scr[:, :1]
@@ -81,6 +81,9 @@ def decode_attention_kernel(q, k, v, kpos, pos, *, window=None, chunk=None,
     nk = C // block_k
 
     qg = q.reshape(B, Kh, G, D)
+    # kpos as one row: a 1-D block gets a different tiled layout from
+    # Mosaic than from XLA, which the TPU compiler refuses.
+    kpos_row = kpos.reshape(1, C)
     pos_arr = jnp.asarray(pos, jnp.int32).reshape(1)
     kernel = functools.partial(_decode_kernel, scale=scale, window=window,
                                chunk=chunk, block_k=block_k, num_k=nk)
@@ -89,7 +92,7 @@ def decode_attention_kernel(q, k, v, kpos, pos, *, window=None, chunk=None,
         grid=(B, Kh, nk),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),             # pos scalar
-            pl.BlockSpec((block_k,), lambda b, h, j: (j,)),    # kpos tile
+            pl.BlockSpec((1, block_k), lambda b, h, j: (0, j)),  # kpos tile
             pl.BlockSpec((1, 1, G, D), lambda b, h, j: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, j: (b, h, j, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, j: (b, h, j, 0)),
@@ -102,5 +105,5 @@ def decode_attention_kernel(q, k, v, kpos, pos, *, window=None, chunk=None,
             pltpu.VMEM((G, D), jnp.float32),
         ],
         interpret=interpret,
-    )(pos_arr, kpos, qg, k, v)
+    )(pos_arr, kpos_row, qg, k, v)
     return out.reshape(B, H, D)

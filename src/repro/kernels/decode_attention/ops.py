@@ -9,15 +9,10 @@ from repro.kernels.decode_attention.kernel import decode_attention_kernel
 from repro.kernels.decode_attention.ref import decode_attention_ref  # noqa: F401
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=(
     "window", "chunk", "scale", "block_k", "interpret"))
 def decode_attention(q, k, v, kpos, pos, *, window=None, chunk=None,
-                     scale=None, block_k=256, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
+                     scale=None, block_k=256, interpret=False):
     return decode_attention_kernel(
         q, k, v, kpos, pos, window=window, chunk=chunk, scale=scale,
         block_k=block_k, interpret=interpret)

@@ -7,6 +7,14 @@ module never touches jax device state — the dry-run sets
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """Mesh with ``Auto`` axes: the model code places values with
+    ``with_sharding_constraint``, which ``Explicit`` axes (the
+    ``jax.make_mesh`` default) refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -14,12 +22,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     (2, 16, 16) = 512 chips ("pod", "data", "model")."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh for CPU smoke testing of the launch path."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 # v5e hardware constants for the roofline (per chip)

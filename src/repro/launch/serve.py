@@ -1,12 +1,15 @@
-"""Serving driver: hosts reduced-scale services on the FIKIT engine with
-batched requests — the end-to-end serving example path, plus the ops
+"""Serving driver: hosts a high/low priority service pair on the FIKIT
+engine with batched requests — the end-to-end serving path, plus the ops
 plane's operator CLI.
+
+Models run at their registered (published) widths; ``--reduced`` swaps
+in the small smoke-test variant of each for CPU runs.
 
     # serve (the original flat invocation still works — "submit" is the
     # default verb):
     PYTHONPATH=src python -m repro.launch.serve \
-        --high qwen3-4b --low mamba2-2.7b --mode fikit --requests 10 \
-        --discipline sjf
+        --high qwen3-4b --low stablelm-1.6b --mode fikit --requests 10 \
+        --discipline sjf --reduced
 
     # durable serving + crash recovery:
     ... serve submit --jobstore /tmp/fikit.db --resume
@@ -24,14 +27,17 @@ plane's operator CLI.
     # optionally diurnal-modulated low-priority; per-QoS-class latency,
     # goodput, shed/reject counts):
     ... serve load --high qwen3-4b --low mamba2-2.7b \
-        --rate 30 --duration 2 --deadline 0.5 --diurnal
+        --rate 30 --duration 2 --deadline 0.5 --diurnal --reduced
 """
 from __future__ import annotations
 
 import argparse
+import os
 import random
 import statistics as st
 import sys as _sys
+import time
+from pathlib import Path
 
 from repro.core.jobstore import JobStore
 from repro.core.queues import QUEUE_DISCIPLINES
@@ -44,8 +50,33 @@ from repro.serving.loadgen import (diurnal_arrivals, merge_schedules,
 # pure-store verbs (status, controls, workers) must start in
 # milliseconds.
 
+#: Persistent compile cache used when ``JAX_COMPILATION_CACHE_DIR`` is
+#: unset. A fixed path inside the checkout: the path is part of the
+#: cache's key, so a moving directory would never hit.
+REPO_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
 
-def serve_pair(high: str, low: str, mode: str = "fikit", requests: int = 8,
+
+def use_compile_cache() -> None:
+    """Keep JAX's persistent compile cache where ``JAX_COMPILATION_CACHE_DIR``
+    says (JAX reads that itself), else in ``REPO_COMPILE_CACHE``. Call
+    before the first compile."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+        jax.config.update("jax_compilation_cache_dir",
+                          str(REPO_COMPILE_CACHE))
+
+
+def _device_memory() -> dict:
+    """Bytes in use and peak bytes on the first device, where the backend
+    reports them (empty otherwise)."""
+    import jax
+    stats = jax.devices()[0].memory_stats() or {}
+    return {f"device_{k}": stats[k] for k in ("bytes_in_use",
+                                               "peak_bytes_in_use")
+            if k in stats}
+
+
+def serve_pair(high, low, mode: str = "fikit", requests: int = 8,
                measure_runs: int = 4, batch: int = 2, seq: int = 48,
                host_gap: float = 0.002, devices: int = 1,
                discipline: str = "fifo", deadline: float = None,
@@ -53,6 +84,11 @@ def serve_pair(high: str, low: str, mode: str = "fikit", requests: int = 8,
                jobstore: str = None, resume: bool = False,
                verbose: bool = True):
     """Host a high/low priority service pair on the wall-clock engine.
+
+    ``high``/``low`` are ``ModelConfig``s, served at the width they give.
+    ``setup_*_s`` in the result is each service's parameter init plus the
+    compile and first run of its segments; ``device_*`` are the first
+    device's memory counters once both services are onboarded.
 
     ``discipline`` is the intra-device queue discipline ("fifo"/"sjf"/
     "edf"); ``deadline`` optionally gives every LOW-priority invocation a
@@ -69,23 +105,25 @@ def serve_pair(high: str, low: str, mode: str = "fikit", requests: int = 8,
     (cancel/pause/resume/drain, see ``main``) act on this run through
     the shared store; ``resume=True`` first re-runs every invocation a
     previous (killed) run left incomplete in the store."""
-    from repro.config import get_config
     from repro.serving import InferenceService, ServingSystem
-    hi = InferenceService(get_config(high).reduced(), priority=0,
-                          batch=batch, seq=seq, host_gap=host_gap)
-    lo = InferenceService(get_config(low).reduced(), priority=5,
-                          batch=batch * 2, seq=seq)
+
+    def setup(cfg, **kw):
+        t0 = time.perf_counter()
+        svc = InferenceService(cfg, **kw)
+        svc.svc.warmup()
+        return svc, time.perf_counter() - t0
+
+    hi, setup_hi = setup(high, priority=0, batch=batch, seq=seq,
+                         host_gap=host_gap)
+    lo, setup_lo = setup(low, priority=5, batch=batch * 2, seq=seq)
     with ServingSystem(Mode(mode), measure_runs=measure_runs,
                        devices=devices,
                        queue_discipline=discipline,
                        online_measure=online_measure,
                        jobstore=jobstore) as sys_:
         meas_hi = sys_.onboard(hi)
-        if online_measure:
-            lo.svc.warmup()            # compile outside the timed phase
-            meas_lo = []
-        else:
-            meas_lo = sys_.onboard(lo)
+        meas_lo = [] if online_measure else sys_.onboard(lo)
+        memory = _device_memory()
         recovered = sys_.recover([hi, lo]) if (resume and jobstore) else []
         res = sys_.invoke_concurrent([
             ("high", hi, requests, 0.0, 0.01),
@@ -103,12 +141,17 @@ def serve_pair(high: str, low: str, mode: str = "fikit", requests: int = 8,
         "devices": devices,
         "discipline": discipline,
         "online_measure": online_measure,
+        "setup_high_s": setup_hi,
+        "setup_low_s": setup_lo,
+        **memory,
         "measure_high_ms": 1e3 * st.mean(meas_hi),
         "measure_low_ms": 1e3 * st.mean(meas_lo) if meas_lo else 0.0,
         "high_jct_ms": 1e3 * st.mean(res["high"]),
         "low_jct_ms": 1e3 * st.mean(res["low"]),
         "high_jct_cv": (st.pstdev(res["high"]) / st.mean(res["high"])),
         "low_jct_cv": (st.pstdev(res["low"]) / st.mean(res["low"])),
+        "high_completed": len(res["high"]),
+        "low_completed": len(res["low"]),
         "fills": fills,
         "steals": steals,
         "deadline_misses": misses,
@@ -130,13 +173,14 @@ def serve_pair(high: str, low: str, mode: str = "fikit", requests: int = 8,
     return out
 
 
-def serve_load(high: str, low: str, mode: str = "fikit",
+def serve_load(high, low, mode: str = "fikit",
                rate: float = 20.0, duration: float = 2.0,
                hi_share: float = 0.3, deadline: float = None,
                diurnal: bool = False, speed: float = 1.0,
                measure_runs: int = 3, devices: int = 1, seed: int = 0,
                verbose: bool = True):
-    """Open-loop traffic through the admission plane: the high service
+    """Open-loop traffic through the admission plane (``high``/``low``
+    are ``ModelConfig``s, as in ``serve_pair``): the high service
     maps to the ``gold`` QoS class (FIKIT Q0), the low service to
     ``bronze`` (Q5). Arrivals are drawn up front (Poisson at ``rate``
     req/s total, split by ``hi_share``; ``diurnal=True`` modulates the
@@ -146,12 +190,9 @@ def serve_load(high: str, low: str, mode: str = "fikit",
     with ``deadline`` set, SLO shedding. The measurement phase's JCTs
     prime the plane's service-time EMA, so shedding is informed from the
     first request."""
-    from repro.config import get_config
     from repro.serving import InferenceService, QoSClass, ServingSystem
-    hi = InferenceService(get_config(high).reduced(), priority=0,
-                          batch=1, seq=32)
-    lo = InferenceService(get_config(low).reduced(), priority=5,
-                          batch=2, seq=32)
+    hi = InferenceService(high, priority=0, batch=1, seq=32)
+    lo = InferenceService(low, priority=5, batch=2, seq=32)
     classes = (QoSClass("gold", priority=0, queue_limit=64,
                         deadline=deadline, max_batch=4),
                QoSClass("bronze", priority=5, queue_limit=256,
@@ -203,8 +244,16 @@ VERBS = ("submit", "load", "status", "cancel", "pause", "resume", "drain",
 WORKER_VERBS = ("run", "status", "stop")
 
 
+def _pair_configs(args):
+    """The CLI's (high, low) configs: registered widths, or the reduced
+    smoke-test variants under ``--reduced``."""
+    from repro.config import get_config
+    cfgs = (get_config(args.high), get_config(args.low))
+    return tuple(c.reduced() for c in cfgs) if args.reduced else cfgs
+
+
 def _cmd_submit(args) -> None:
-    serve_pair(args.high, args.low, args.mode, args.requests,
+    serve_pair(*_pair_configs(args), args.mode, args.requests,
                devices=args.devices, discipline=args.discipline,
                deadline=args.deadline, online_measure=args.online_measure,
                jobstore=args.jobstore, resume=args.resume)
@@ -342,6 +391,10 @@ def main(argv=None):
                     help="replay speedup (2.0 = twice as fast)")
     lp.add_argument("--devices", type=int, default=1)
     lp.add_argument("--seed", type=int, default=0)
+    for p in (sp, lp):
+        p.add_argument("--reduced", action="store_true",
+                       help="serve the reduced smoke-test variant of each "
+                            "model (2 layers, float32) — for CPU runs")
 
     st_ = sub.add_parser("status", help="print the store's job table")
     _add_store_arg(st_)
@@ -391,10 +444,12 @@ def main(argv=None):
         _add_store_arg(vp)
 
     args = ap.parse_args(argv)
+    if args.verb in ("submit", "load"):
+        use_compile_cache()
     if args.verb == "submit":
         _cmd_submit(args)
     elif args.verb == "load":
-        serve_load(args.high, args.low, args.mode, rate=args.rate,
+        serve_load(*_pair_configs(args), args.mode, rate=args.rate,
                    duration=args.duration, hi_share=args.hi_share,
                    deadline=args.deadline, diurnal=args.diurnal,
                    speed=args.speed, devices=args.devices, seed=args.seed)

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import zlib
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +18,9 @@ def cdtype(cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 # Init
 # ---------------------------------------------------------------------------
+# Each init is one jitted program, so the float32 draw is fused into the
+# cast and never held on the device beside the stored leaf.
+@partial(jax.jit, static_argnames=("shape", "dtype", "scale"))
 def dense_init(key, shape, dtype, scale: float = 1.0):
     """Truncated-normal fan-in init."""
     fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
@@ -25,6 +29,7 @@ def dense_init(key, shape, dtype, scale: float = 1.0):
             * std).astype(dtype)
 
 
+@partial(jax.jit, static_argnames=("shape", "dtype"))
 def embed_init(key, shape, dtype):
     return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
 

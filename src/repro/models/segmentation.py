@@ -2,10 +2,10 @@
 (program segments) for the FIKIT scheduler.
 
 A service's inference = [embed] + [layer]*L + [head]. The layer segment is
-ONE jitted program reused for every layer (layer params are an argument), so
-all L dispatches share a KernelID — exactly the paper's observation that a
-task repeatedly calls kernels with the same ID (Fig 5), and the reason SK
-averaging + runtime feedback exist.
+ONE jitted program reused for every layer (the stacked layer params and the
+layer index are arguments), so all L dispatches share a KernelID — exactly
+the paper's observation that a task repeatedly calls kernels with the same
+ID (Fig 5), and the reason SK averaging + runtime feedback exist.
 
 Host work (tokenize / sample / detokenize) runs client-side between
 segments — the genuine origin of inter-kernel device idle gaps.
@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from repro.config import DENSE, ENCDEC, HYBRID, MOE, SSM, VLM, ModelConfig
 from repro.core.client import Segment
-from repro.models import mamba2, moe, rglru, transformer as tfm, vlm as vlm_m
+from repro.models import mamba2, moe, rglru, transformer as tfm
 from repro.models.layers import rms_norm
 
 
@@ -40,8 +40,28 @@ def _sleep_work(seconds: float) -> Optional[Callable]:
     return work
 
 
+def _positions(S):
+    return jnp.arange(S, dtype=jnp.int32)
+
+
+def _layer_of(stack, i):
+    """Layer ``i`` of a stacked layer tree, sliced inside the program that
+    uses it: the stack stays the only copy of the weights, and ``i`` is an
+    argument, so every layer runs the same compiled program."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), stack)
+
+
 class SegmentedService:
-    """A reduced-scale model packaged as FIKIT-schedulable segments.
+    """A model packaged as FIKIT-schedulable segments.
+
+    Every segment is a jitted program that takes its parameters as
+    arguments (a closed-over array would be baked into the executable as
+    a constant, a second copy of the weights on the device). ``programs``
+    maps each distinct program to its jitted function. No program or
+    segment refers back to the service: a reference cycle would keep the
+    params on the device after the service is dropped, until the next
+    garbage collection.
 
     host_gap: host think-time injected after each layer segment (models
     the CPU-side work real serving stacks do between dispatches).
@@ -55,6 +75,7 @@ class SegmentedService:
         self.seq = seq
         self.host_gap = host_gap
         self.tail_gap = tail_gap
+        self.programs = {}
         self._build()
 
     # ------------------------------------------------------------- builders
@@ -69,68 +90,83 @@ class SegmentedService:
         else:  # pragma: no cover
             raise ValueError(cfg.family)
 
-    def _positions(self, S):
-        return jnp.arange(S, dtype=jnp.int32)
+    def _entry(self, name: str, fn, host_work=None) -> Segment:
+        """A segment that runs program ``name`` on the whole param tree
+        and waits for its result."""
+        prog = self.programs[name] = jax.jit(fn)
+        params = self.params
+        return Segment(f"{self.cfg.name}/{name}",
+                       lambda state: _sync(prog(params, state)),
+                       host_work=host_work)
+
+    def _stacked_layers(self, name: str, prog, stack, statics=None):
+        """One segment per layer of ``stack``, all on program ``prog``;
+        ``statics[i]`` (if given) is layer i's static argument."""
+        self.programs[name] = prog
+        L = jax.tree.leaves(stack)[0].shape[0]
+        segs = []
+        for i in range(L):
+            args = (stack, jnp.asarray(i, jnp.int32))
+            if statics is not None:
+                args += (statics[i],)
+            segs.append(Segment(
+                f"{self.cfg.name}/{name}",
+                partial(self._run_layer, prog, args),
+                host_work=_sleep_work(self.host_gap)))
+        return segs
+
+    @staticmethod
+    def _run_layer(prog, args, x):
+        return _sync(prog(*args, x))
+
+    def _head(self, unpack=lambda state: state) -> Segment:
+        cfg = self.cfg
+        return self._entry(
+            "head", lambda p, state: tfm.unembed(p, unpack(state), cfg),
+            host_work=self._sample_work())
 
     def _build_decoder_lm(self):
-        cfg, params = self.cfg, self.params
+        cfg = self.cfg
 
-        @jax.jit
-        def embed(tokens):
+        def embed(p, batch):
             if cfg.family == VLM:
-                patches = vlm_m.stub_patches(cfg, tokens.shape[0])
-                return _sync(tfm.embed_tokens(params, tokens, cfg, patches))
-            return _sync(tfm.embed_tokens(params, tokens, cfg))
+                patches, tokens = batch
+                return tfm.embed_tokens(p, tokens, cfg, patches)
+            return tfm.embed_tokens(p, batch, cfg)
 
         if cfg.family == MOE:
-            def _layer(lp, x, i):
-                pat = cfg.chunk_pattern or 1
-                is_full = bool(cfg.chunk_pattern) and (i + 1) % pat == 0
+            def layer(stack, i, is_full, x):
                 window, chunk = ((cfg.sliding_window, None) if is_full
                                  else (cfg.sliding_window,
                                        cfg.attention_chunk))
-                y, _aux = moe.layer_apply(lp, x, self._positions(x.shape[1]),
-                                          cfg, window=window, chunk=chunk)
+                y, _aux = moe.layer_apply(
+                    _layer_of(stack, i), x, _positions(x.shape[1]),
+                    cfg, window=window, chunk=chunk)
                 return y
-            layer = jax.jit(_layer, static_argnums=(2,))
+            prog = jax.jit(layer, static_argnums=(2,))
+            pat = cfg.chunk_pattern or 1
+            statics = [bool(cfg.chunk_pattern) and (i + 1) % pat == 0
+                       for i in range(cfg.num_layers)]
         elif cfg.family == SSM:
-            layer = jax.jit(lambda lp, x, i: mamba2.layer_apply(lp, x, cfg),
-                            static_argnums=(2,))
+            def layer(stack, i, x):
+                return mamba2.layer_apply(_layer_of(stack, i), x, cfg)
+            prog, statics = jax.jit(layer), None
         else:
-            def _layer(lp, x, i):
-                return tfm.layer_apply(lp, x, self._positions(x.shape[1]),
-                                       cfg, window=cfg.sliding_window,
-                                       chunk=cfg.attention_chunk)
-            layer = jax.jit(_layer, static_argnums=(2,))
+            def layer(stack, i, x):
+                return tfm.layer_apply(
+                    _layer_of(stack, i), x, _positions(x.shape[1]),
+                    cfg, window=cfg.sliding_window,
+                    chunk=cfg.attention_chunk)
+            prog, statics = jax.jit(layer), None
 
-        @jax.jit
-        def head(x):
-            return _sync(tfm.unembed(params, x, cfg))
-
-        L = cfg.num_layers
-        lps = [jax.tree.map(lambda a, i=i: a[i], params["layers"])
-               for i in range(L)]
-        segs = [Segment(f"{cfg.name}/embed", lambda t: embed(t))]
-        for i in range(L):
-            segs.append(Segment(
-                f"{cfg.name}/layer",
-                partial(self._run_layer, layer, lps[i], i),
-                host_work=_sleep_work(self.host_gap)))
-        segs.append(Segment(f"{cfg.name}/head", lambda x: head(x),
-                            host_work=self._sample_work()))
-        self.segments = segs
-
-    @staticmethod
-    def _run_layer(layer, lp, i, x):
-        return _sync(layer(lp, x, i))
+        self.segments = (
+            [self._entry("embed", embed)]
+            + self._stacked_layers("layer", prog, self.params["layers"],
+                                   statics)
+            + [self._head()])
 
     def _build_hybrid(self):
-        cfg, params = self.cfg, self.params
-        kinds = rglru.block_kinds(cfg)
-
-        @jax.jit
-        def embed(tokens):
-            return _sync(tfm.embed_tokens(params, tokens, cfg))
+        cfg = self.cfg
 
         def rec_block(lp, x):
             x = rglru._rec_apply(lp, x, cfg)
@@ -139,63 +175,42 @@ class SegmentedService:
         def attn_block(lp, x):
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
             x = x + tfm.attn_apply_full(lp["attn"], h,
-                                        self._positions(x.shape[1]), cfg,
+                                        _positions(x.shape[1]), cfg,
                                         window=cfg.local_window)
             return rglru._mlp_res(lp, x, cfg)
 
-        rec_j, attn_j = jax.jit(rec_block), jax.jit(attn_block)
-
-        @jax.jit
-        def head(x):
-            return _sync(tfm.unembed(params, x, cfg))
-
-        segs = [Segment(f"{cfg.name}/embed", lambda t: embed(t))]
-        for lp, kind in zip(params["blocks"], kinds):
-            fn = rec_j if kind == "rec" else attn_j
+        progs = {"rec": jax.jit(rec_block), "attn": jax.jit(attn_block)}
+        self.programs.update(progs)
+        segs = [self._entry(
+            "embed", lambda p, tokens: tfm.embed_tokens(p, tokens, cfg))]
+        for lp, kind in zip(self.params["blocks"],
+                            rglru.block_kinds(cfg)):
             segs.append(Segment(
                 f"{cfg.name}/{kind}",
-                partial(lambda f, p, x: _sync(f(p, x)), fn, lp),
+                partial(self._run_layer, progs[kind], (lp,)),
                 host_work=_sleep_work(self.host_gap)))
-        segs.append(Segment(f"{cfg.name}/head", lambda x: head(x),
-                            host_work=self._sample_work()))
-        self.segments = segs
+        self.segments = segs + [self._head()]
 
     def _build_encdec(self):
-        cfg, params = self.cfg, self.params
-
-        @jax.jit
-        def encode(batch):
-            frames, tokens = batch
-            return _sync((encdec_encode(params, frames, cfg),
-                          tfm.embed_tokens(params, tokens, cfg)))
-
         from repro.models import encdec as ed
-        encdec_encode = ed.encode
+        cfg = self.cfg
 
-        def dec_layer(lp, state):
+        def encode(p, batch):
+            frames, tokens = batch
+            return (ed.encode(p, frames, cfg),
+                    tfm.embed_tokens(p, tokens, cfg))
+
+        def dec_layer(stack, i, state):
             enc_out, x = state
-            x = ed._dec_layer(lp, x, self._positions(x.shape[1]), enc_out,
-                              cfg)
+            x = ed._dec_layer(_layer_of(stack, i), x,
+                              _positions(x.shape[1]), enc_out, cfg)
             return (enc_out, x)
-        dec_j = jax.jit(dec_layer)
 
-        @jax.jit
-        def head(state):
-            _, x = state
-            return _sync(tfm.unembed(params, x, cfg))
-
-        Ld = cfg.num_decoder_layers or cfg.num_layers
-        lps = [jax.tree.map(lambda a, i=i: a[i], params["dec_layers"])
-               for i in range(Ld)]
-        segs = [Segment(f"{cfg.name}/encode", lambda b: encode(b))]
-        for i in range(Ld):
-            segs.append(Segment(
-                f"{cfg.name}/dec_layer",
-                partial(lambda p, s: _sync(dec_j(p, s)), lps[i]),
-                host_work=_sleep_work(self.host_gap)))
-        segs.append(Segment(f"{cfg.name}/head", lambda s: head(s),
-                            host_work=self._sample_work()))
-        self.segments = segs
+        self.segments = (
+            [self._entry("encode", encode)]
+            + self._stacked_layers("dec_layer", jax.jit(dec_layer),
+                                   self.params["dec_layers"])
+            + [self._head(unpack=lambda state: state[1])])
 
     # -------------------------------------------------------------- helpers
     def _sample_work(self):
@@ -220,6 +235,4 @@ class SegmentedService:
         state = self.make_input()
         for seg in self.segments:
             state = seg.fn(state)
-            if seg.host_work is not None and seg is self.segments[-1]:
-                pass
         return True

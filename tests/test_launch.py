@@ -56,7 +56,8 @@ def test_train_step_executes_and_updates():
 
 def test_param_specs_divisibility_guard():
     cfg = get_config("qwen3-4b")      # kv_heads=8, not divisible by 16
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
     from repro.models import api
     params = api.build_params(cfg, key=None)
