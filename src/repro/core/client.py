@@ -21,6 +21,7 @@ import itertools
 import time
 from typing import Callable, Optional, Sequence, Tuple
 
+from repro.core import spans
 from repro.core.executor import WallClockEngine
 from repro.core.kernel_id import KernelID, kernel_id_for
 from repro.core.profiler import Profiler
@@ -96,7 +97,9 @@ class HookClient:
                 fut = self.engine.submit(req)
                 state, _, _ = fut.result()
                 if seg.host_work is not None:
-                    state = seg.host_work(state)
+                    state = spans.run_host_work(
+                        seg.host_work, state, req,
+                        self.engine.device_of(inst))
         finally:
             self.engine.task_end(inst)
         return state, time.perf_counter() - t_begin
@@ -143,7 +146,9 @@ class HookClient:
                     return
                 try:
                     if seg.host_work is not None:
-                        out = seg.host_work(out)
+                        out = spans.run_host_work(
+                            seg.host_work, out, req,
+                            self.engine.device_of(inst))
                     if i + 1 < len(segments):
                         step(i + 1, out)
                     else:

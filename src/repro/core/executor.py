@@ -42,6 +42,7 @@ from concurrent.futures import Future
 from dataclasses import dataclass
 from typing import Dict, List, Optional
 
+from repro.core import spans
 from repro.core.fikit import EPSILON
 from repro.core.interference import InterferenceModel
 from repro.core.online import OnlineConfig, OnlineMeasurement
@@ -51,13 +52,32 @@ from repro.core.profiler import ProfiledData
 from repro.core.task import KernelRequest, TaskKey
 
 
-@dataclass
+@dataclass(slots=True)
 class ExecRecord:
+    """One dispatched segment's turn on a device thread, stamped on the
+    ``perf_counter`` clock: ``launch`` (pushed onto the device queue),
+    ``start`` (dequeued), ``dispatched`` (its jitted call returned, before
+    the wait), ``end`` (the wait returned), ``booked`` (completion
+    bookkeeping done, engine lock released) and ``released`` (completion
+    callback returned; the device thread is free). The request keeps its
+    ``submit_time`` but not its payload. Written to the span log
+    (``repro.core.spans``) when the turn ends."""
     req: KernelRequest
     start: float
     end: float
     filler: bool = False
     device: int = 0
+    launch: float = 0.0
+    dispatched: float = 0.0
+    booked: float = 0.0
+    released: float = 0.0
+
+    def span(self) -> tuple:
+        req = self.req
+        return (spans.SEGMENT, req.task_instance, req.seq_index,
+                req.task_key.process, req.priority, self.device,
+                self.filler, req.submit_time, self.launch, self.start,
+                self.dispatched, self.end, self.booked, self.released)
 
 
 class JobCancelled(RuntimeError):
@@ -131,7 +151,9 @@ class WallClockEngine:
                                         launch=self._device_launch,
                                         threadsafe=True, trace=trace,
                                         online=self.online,
-                                        interference=self.interference)
+                                        interference=self.interference,
+                                        gap_logs=[spans.GapLog(d) for d
+                                                  in range(devices)])
         # single-device alias kept for callers that inspect decision state
         self.policy = self.placement.policies[0]
         self._device_qs: List["queue.Queue"] = [queue.Queue()
@@ -202,8 +224,9 @@ class WallClockEngine:
             item = dq.get()
             if item is None or self._stop:
                 break
-            req, fut, filler = item
+            req, fut, filler, t_launch = item
             t0 = time.perf_counter()
+            tm = spans.annotation("fikit/segment")
             out = err = None
             try:
                 out = req.payload()
@@ -213,24 +236,42 @@ class WallClockEngine:
                 t1 = time.perf_counter()
                 err = e
                 fut.set_exception(e)
+            # a kept record pins no segment input on the device
+            req.payload = None
+            rec = ExecRecord(req, t0, t1, filler, device, t_launch,
+                             spans.dispatched_since(t0, t1))
+            # the phases are annotated only inside an annotated segment
+            book = tm and spans.annotation("fikit/segment/book")
             with self._lock:
                 if self._on_kernel_complete is not None:
                     # write-ahead: the durable record commits BEFORE the
                     # boundary's scheduling side-effects
                     self._on_kernel_complete(req, t0, t1)
                 self._futures.pop(req.uid, None)   # resolved: stop pinning it
-                self._records.append(ExecRecord(req, t0, t1, filler, device))
+                self._records.append(rec)
                 if filler:
                     self.placement.fill_complete(device)
                 self.placement.kernel_end(req.task_instance, req.kernel_id,
                                           start=t0, end=t1)
                 cb = self._done_cbs.pop(req.uid, None)
+            rec.booked = time.perf_counter()
+            if book is not None:
+                spans.close(book)
             if cb is not None:
                 # completion callback AFTER the boundary's scheduling
                 # side-effects, OUTSIDE the lock: the callee may submit
                 # the stream's next request or retire the task without
                 # parking a thread on the Future (admission-plane seam)
+                call = tm and spans.annotation("fikit/segment/callback")
                 cb(req, out, t0, t1, err)
+                if call is not None:
+                    spans.close(call)
+            rec.released = time.perf_counter()
+            spans.record(rec.span())
+            if tm is not None:
+                spans.close(tm, submit=req.submit_time, launch=t_launch,
+                            start=t0, dispatched=rec.dispatched, end=t1,
+                            booked=rec.booked, released=rec.released)
 
     # ----------------------------------------------------------- task control
     def task_begin(self, instance: int, key: TaskKey, priority: int) -> None:
@@ -354,7 +395,11 @@ class WallClockEngine:
         fut = self._futures.get(req.uid)
         if fut is None:                            # pragma: no cover
             fut = self._futures[req.uid] = Future()
-        self._device_qs[device].put((req, fut, filler))
+        self._device_qs[device].put((req, fut, filler, time.perf_counter()))
+
+    def device_of(self, instance: int) -> int:
+        """The device a live task runs on, or -1."""
+        return self.placement.device_of(instance)
 
     # ------------------------------------------------------------------ info
     @property

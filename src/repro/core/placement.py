@@ -144,7 +144,10 @@ class PlacementLayer:
                  trace: TraceSpec = "list",
                  reference: bool = False,
                  online=None,
-                 interference=None):
+                 interference=None,
+                 gap_logs=None):
+        """``gap_logs`` (None, or one ``repro.core.spans.GapLog`` per
+        device) records the gaps each device's policy opens."""
         if launch is None:
             raise TypeError("PlacementLayer requires a launch hook")
         if devices < 1:
@@ -193,7 +196,8 @@ class PlacementLayer:
                         launch=device_launcher(d), threadsafe=threadsafe,
                         trace=trace, discipline=queue_discipline,
                         reference=reference, online=online,
-                        interference=interference)
+                        interference=interference,
+                        gap_log=gap_logs[d] if gap_logs else None)
             for d in range(devices)]
 
         self._device_of: Dict[int, int] = {}
@@ -297,6 +301,10 @@ class PlacementLayer:
 
     def fill_complete(self, device: int) -> None:
         self.policies[device].fill_complete()
+
+    def device_of(self, instance: int) -> int:
+        """The device a live task runs on, or -1."""
+        return self._device_of.get(instance, -1)
 
     def kernel_end(self, instance: int, kernel_id, *, last: bool = False,
                    actual_gap: Optional[float] = None,

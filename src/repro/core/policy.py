@@ -226,6 +226,10 @@ class FikitPolicy:
     coefficient-scaled effective duration. With ``interference=None``
     (the pinned default) or a disabled model every decision is
     bit-identical to the pre-interference implementation.
+
+    ``gap_log`` optionally attaches a ``repro.core.spans.GapLog`` that
+    records each gap's predicted and actual length and its fills; it
+    never changes a decision.
     """
 
     def __init__(self, mode: Mode,
@@ -239,7 +243,8 @@ class FikitPolicy:
                  discipline: QueueDisciplineSpec = "fifo",
                  reference: bool = False,
                  online=None,
-                 interference=None):
+                 interference=None,
+                 gap_log=None):
         if launch is None:
             raise TypeError("FikitPolicy requires a launch hook")
         self.mode = mode
@@ -279,6 +284,9 @@ class FikitPolicy:
         #: interference environment reads it to slow concurrent fillers.
         self.gap_kinfo: Optional[Tuple[int, KernelID]] = None
         self._gap_class: Optional[str] = None
+        #: optional ``repro.core.spans.GapLog``: told of every gap's open,
+        #: fills, close and fill completions; never read by a decision
+        self._gaps = gap_log
         self.fills_in_flight = 0
         self.fill_count = 0
         self.overshoot_time = 0.0
@@ -327,10 +335,7 @@ class FikitPolicy:
                         self.trace.append(("admit", nxt))
                     admitted.append(nxt)
         elif self.mode in QUEUED_MODES:
-            self.gap_open = False
-            self.gap_remaining = 0.0
-            self.gap_kinfo = None
-            self._gap_class = None
+            self._drop_gap("end")
             self._release_new_holder()
         self._note_holder()
         return admitted
@@ -410,10 +415,7 @@ class FikitPolicy:
         was_holder = self.holder() == instance
         at, reqs = self.detach_task(instance, reqs)
         if was_holder and self.mode in QUEUED_MODES:
-            self.gap_open = False
-            self.gap_remaining = 0.0
-            self.gap_kinfo = None
-            self._gap_class = None
+            self._drop_gap("pause")
             self._release_new_holder()
         return at, reqs
 
@@ -463,7 +465,7 @@ class FikitPolicy:
         holder = self.holder()
         if holder is None or holder == req.task_instance:
             if self.gap_open and holder == req.task_instance:
-                self._close_gap(holder)            # real-time feedback
+                self._close_gap(holder, req.seq_index)   # real-time feedback
             self._launch(req)
             return True
         if (self.active[req.task_instance].priority
@@ -491,6 +493,8 @@ class FikitPolicy:
             self.spurious_fill_completions += 1
             return
         self.fills_in_flight -= 1
+        if self._gaps is not None:
+            self._gaps.fill_done()
         now = self._clock()
         if self.gap_end_actual is not None and now > self.gap_end_actual:
             self.overshoot_time += now - self.gap_end_actual
@@ -517,6 +521,8 @@ class FikitPolicy:
                 # the predicted SG is about to stand in for
                 self.online.observe_gap_error(predicted, actual_gap)
             if predicted > self.epsilon:           # skip small gaps
+                if self._gaps is not None:
+                    self._gaps.open(at, predicted)
                 self.gap_open = True
                 self.gap_remaining = predicted
                 self.gap_kinfo = (instance, kernel_id)
@@ -531,11 +537,18 @@ class FikitPolicy:
         self.try_fill()
 
     # ------------------------------------------------------------ gap + fill
-    def _close_gap(self, holder: int) -> None:
+    def _drop_gap(self, by: str, seq: int = -1) -> None:
+        """Reset the gap state; the gap log (if any) records why (``by``)
+        and, for a closing submit, its segment (``seq``)."""
+        if self.gap_open and self._gaps is not None:
+            self._gaps.close(seq, by)
         self.gap_open = False
         self.gap_remaining = 0.0
         self.gap_kinfo = None
         self._gap_class = None
+
+    def _close_gap(self, holder: int, seq: int) -> None:
+        self._drop_gap("submit", seq)
         if self.feedback and self.gap_end_actual is None:
             # wall-clock feedback: the holder's submit IS the gap's end
             self.gap_end_actual = self._clock()
@@ -571,6 +584,8 @@ class FikitPolicy:
                                                req.kernel_id,
                                                self._gap_class, fclass)
             self.gap_remaining -= eff
+            if self._gaps is not None:
+                self._gaps.fill()
             self._launch(req, filler=True, tag="fill")
 
     def _release_new_holder(self) -> None:

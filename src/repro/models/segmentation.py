@@ -20,13 +20,19 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import DENSE, ENCDEC, HYBRID, MOE, SSM, VLM, ModelConfig
+from repro.core import spans
 from repro.core.client import Segment
 from repro.models import mamba2, moe, rglru, transformer as tfm
 from repro.models.layers import rms_norm
 
 
 def _sync(x):
+    """Wait for a segment's result: the one place every served segment
+    waits, so the dispatch stamp of its span is taken here."""
+    tm = spans.begin_wait()
     jax.block_until_ready(x)
+    if tm is not None:
+        spans.close(tm)
     return x
 
 
@@ -58,7 +64,9 @@ class SegmentedService:
     Every segment is a jitted program that takes its parameters as
     arguments (a closed-over array would be baked into the executable as
     a constant, a second copy of the weights on the device). ``programs``
-    maps each distinct program to its jitted function. No program or
+    maps each distinct program to its jitted function; each compiles as
+    ``jit_<model>.<program>`` (e.g. ``jit_qwen3-4b.layer``), so a device
+    trace tells two services' programs apart. No program or
     segment refers back to the service: a reference cycle would keep the
     params on the device after the service is dropped, until the next
     garbage collection.
@@ -93,11 +101,17 @@ class SegmentedService:
     def _entry(self, name: str, fn, host_work=None) -> Segment:
         """A segment that runs program ``name`` on the whole param tree
         and waits for its result."""
-        prog = self.programs[name] = jax.jit(fn)
+        prog = self.programs[name] = self._jit(fn, name)
         params = self.params
         return Segment(f"{self.cfg.name}/{name}",
                        lambda state: _sync(prog(params, state)),
                        host_work=host_work)
+
+    def _jit(self, fn, program: str, **kw):
+        """``jax.jit(fn)``, named so that it compiles as
+        ``jit_<model>.<program>``."""
+        fn.__name__ = fn.__qualname__ = f"{self.cfg.name}.{program}"
+        return jax.jit(fn, **kw)
 
     def _stacked_layers(self, name: str, prog, stack, statics=None):
         """One segment per layer of ``stack``, all on program ``prog``;
@@ -143,21 +157,21 @@ class SegmentedService:
                     _layer_of(stack, i), x, _positions(x.shape[1]),
                     cfg, window=window, chunk=chunk)
                 return y
-            prog = jax.jit(layer, static_argnums=(2,))
+            prog = self._jit(layer, "layer", static_argnums=(2,))
             pat = cfg.chunk_pattern or 1
             statics = [bool(cfg.chunk_pattern) and (i + 1) % pat == 0
                        for i in range(cfg.num_layers)]
         elif cfg.family == SSM:
             def layer(stack, i, x):
                 return mamba2.layer_apply(_layer_of(stack, i), x, cfg)
-            prog, statics = jax.jit(layer), None
+            prog, statics = self._jit(layer, "layer"), None
         else:
             def layer(stack, i, x):
                 return tfm.layer_apply(
                     _layer_of(stack, i), x, _positions(x.shape[1]),
                     cfg, window=cfg.sliding_window,
                     chunk=cfg.attention_chunk)
-            prog, statics = jax.jit(layer), None
+            prog, statics = self._jit(layer, "layer"), None
 
         self.segments = (
             [self._entry("embed", embed)]
@@ -179,7 +193,8 @@ class SegmentedService:
                                         window=cfg.local_window)
             return rglru._mlp_res(lp, x, cfg)
 
-        progs = {"rec": jax.jit(rec_block), "attn": jax.jit(attn_block)}
+        progs = {"rec": self._jit(rec_block, "rec"),
+                 "attn": self._jit(attn_block, "attn")}
         self.programs.update(progs)
         segs = [self._entry(
             "embed", lambda p, tokens: tfm.embed_tokens(p, tokens, cfg))]
@@ -208,7 +223,8 @@ class SegmentedService:
 
         self.segments = (
             [self._entry("encode", encode)]
-            + self._stacked_layers("dec_layer", jax.jit(dec_layer),
+            + self._stacked_layers("dec_layer",
+                                   self._jit(dec_layer, "dec_layer"),
                                    self.params["dec_layers"])
             + [self._head(unpack=lambda state: state[1])])
 

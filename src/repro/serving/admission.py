@@ -53,6 +53,8 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from repro.core import spans
+
 __all__ = ["QoSClass", "AdmissionTicket", "AdmissionPlane",
            "DEFAULT_CLASSES", "SHARD_ROUTERS", "REJECTED", "SHED",
            "COMPLETED", "FAILED", "CANCELLED", "REQUEUED"]
@@ -66,6 +68,8 @@ CANCELLED = "cancelled"    # an ops-plane cancel verb hit the invocation
 REQUEUED = "requeued"      # still queued at stop(): resubmit later
 
 _UNSET = object()
+# guards each ticket's count of the stamps its span still waits for
+_parts_lock = threading.Lock()
 
 #: Pluggable shard routing for backend dispatch, mirroring the placement
 #: layer's device-election seam: a router maps an admitted (service,
@@ -121,16 +125,24 @@ class AdmissionTicket:
     returns the outcome string. Rejections resolve synchronously inside
     ``submit`` — ``retry_after`` then estimates (seconds) when capacity
     should free up, and ``requeue`` is True when the refusal is a
-    transient not-admitting signal (drain/stop) rather than overload."""
+    transient not-admitting signal (drain/stop) rather than overload.
+
+    ``popped`` (its group left the queue), ``invoked`` (the engine task
+    ``instance`` was launched) and ``resolved`` are the plane-clock stamps
+    of its ``admission`` span (``repro.core.spans``), written once the
+    ticket has resolved and, if it was launched, the launch has
+    returned."""
 
     __slots__ = ("service", "qos", "arrival", "deadline", "outcome",
                  "jct", "latency", "error", "retry_after", "requeue",
-                 "batch_size", "_event")
+                 "batch_size", "_event", "priority", "popped", "invoked",
+                 "instance", "resolved", "_parts", "_tm")
 
     def __init__(self, service, qos: str, arrival: float,
-                 deadline: Optional[float]):
+                 deadline: Optional[float], priority: int = -1):
         self.service = service
         self.qos = qos
+        self.priority = priority
         self.arrival = arrival
         self.deadline = deadline       # absolute, plane clock; None = no SLO
         self.outcome: Optional[str] = None
@@ -141,6 +153,12 @@ class AdmissionTicket:
         self.requeue = False
         self.batch_size = 0
         self._event = threading.Event()
+        self.popped: Optional[float] = None
+        self.invoked: Optional[float] = None
+        self.instance = -1
+        self.resolved: Optional[float] = None
+        self._parts = 1          # stamps the span waits for: the resolve
+        self._tm = spans.annotation("fikit/admission")
 
     @property
     def done(self) -> bool:
@@ -156,11 +174,27 @@ class AdmissionTicket:
                  retry_after=None, requeue=False) -> None:
         self.outcome = outcome
         self.jct = jct
+        self.resolved = now
         self.latency = now - self.arrival
         self.error = error
         self.retry_after = retry_after
         self.requeue = requeue
+        self._part_done()
         self._event.set()
+
+    def _part_done(self) -> None:
+        """One stamp the span waited for is in; the last writes it."""
+        with _parts_lock:
+            self._parts -= 1
+            if self._parts:
+                return
+        spans.record((spans.ADMISSION, self.instance, -1,
+                      getattr(getattr(self.service, "key", None), "process",
+                              ""),
+                      self.priority, -1, self.arrival, self.popped,
+                      self.invoked, self.resolved, self.outcome))
+        spans.close(self._tm, instance=self.instance)
+        self._tm = None
 
     def __repr__(self):
         return (f"AdmissionTicket({self.qos}, outcome={self.outcome}, "
@@ -344,7 +378,8 @@ class AdmissionPlane:
         now = self.clock() if arrival is None else arrival
         rel = st.cls.deadline if deadline is _UNSET else deadline
         abs_deadline = None if rel is None else now + rel
-        t = AdmissionTicket(service, st.cls.name, now, abs_deadline)
+        t = AdmissionTicket(service, st.cls.name, now, abs_deadline,
+                            st.cls.priority)
         if not self.enabled:
             return self._submit_passthrough(st, t, rel)
         retry = (None if self._backend is None else
@@ -464,6 +499,7 @@ class AdmissionPlane:
             while (st.queue and len(members) < st.cls.max_batch
                    and st.queue[0].service is head.service):
                 t = st.queue.popleft()
+                t.popped = now
                 if self._hopeless(t, now):
                     st.shed += 1
                     self._log("shed", st.cls.name, "deadline-unmeetable",
@@ -481,6 +517,7 @@ class AdmissionPlane:
             self._inflight += 1
             for t in members:
                 t.batch_size = len(members)
+                t._parts += 1            # and the launch's return
             self._log("admit", st.cls.name, len(members), higher_queued)
             groups.append((st, members))
         return groups
@@ -504,13 +541,21 @@ class AdmissionPlane:
             rel = max(0.0, min(deadlines) - self.clock())
         def cb(jct, error):
             self._group_done(st, members, jct, error)
-        if self._backend is not None:
-            self._backend.dispatch(
-                members[0].service, cb, deadline=rel,
-                shard=self._shard_of(members[0].service, st.cls.name))
-        else:
-            self._system._invoke_async(members[0].service, cb,
-                                       deadline=rel)
+        inst = -1
+        try:
+            if self._backend is not None:
+                self._backend.dispatch(
+                    members[0].service, cb, deadline=rel,
+                    shard=self._shard_of(members[0].service, st.cls.name))
+            else:
+                inst = self._system._invoke_async(members[0].service, cb,
+                                                  deadline=rel)
+        finally:
+            now = self.clock()
+            for t in members:
+                t.invoked = now
+                t.instance = -1 if inst is None else inst
+                t._part_done()
 
     def _group_done(self, st: _ClassState, members, jct, error) -> None:
         """Completion callback (device thread, no engine lock): resolve

@@ -155,8 +155,10 @@ class ServingSystem:
                 # into the live profile store the engine will serve from
                 for prof in snap._by_key.values():
                     self.profiles.load(prof)
+        # a serving engine runs for as long as it serves: keep only the
+        # newest decisions (docs/ARCHITECTURE.md, "Trace sinks")
         self.engine = WallClockEngine(
-            self.mode, self.profiles, devices=self.devices,
+            self.mode, self.profiles, devices=self.devices, trace="ring",
             discipline=self.discipline,
             queue_discipline=self.queue_discipline,
             online=self.online_measure or None,
