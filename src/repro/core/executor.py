@@ -5,8 +5,8 @@ Roles map 1:1 to the paper's deployment (§3.2):
   dispatches, forwards KernelRequests to the scheduler (paper: LD_PRELOAD
   hook + UDP; here: in-process call + thread-safe queues).
 - ``WallClockEngine`` — the FIKIT scheduler process: the serial device
-  executor thread (the TPU/GPU analog: one program at a time, FIFO) plus
-  the thread-safe shell around the shared scheduling core.
+  executor thread (the TPU/GPU analog: one program at a time) plus the
+  thread-safe shell around the shared scheduling core.
 
 ALL scheduling decisions — holder election, routing, gap open/close with
 real-time feedback, the bounded BestPrioFit fill loop, release-on-task-done,
@@ -17,8 +17,13 @@ live in ``repro.core.placement.PlacementLayer`` (K=1 is a pass-through).
 This engine only adds what the simulator fakes: real threads, a lock,
 Futures, and ``time.perf_counter``.
 
-Each device thread pops launched requests in FIFO order and runs their
-payload callables (jitted JAX segments, block_until_ready inside).
+Each device thread pops launched requests from its ``DeviceQueue`` and
+runs their payload callables (jitted JAX segments, block_until_ready
+inside). In the priority-ordered modes (FIKIT, PREEMPT) the queue serves
+the equal-priority requests at its head in the order their tasks began,
+so a task in flight runs its segments back to back instead of taking
+turns with every other task of its priority; SHARING, EXCLUSIVE and
+``sjf``/``edf`` levels keep launch order.
 ``devices=K`` starts K device threads over K serial queues, one per
 placement device. Everything the simulator models is real here: device
 busy intervals, queue waits, fill overshoot.
@@ -47,7 +52,7 @@ from repro.core.fikit import EPSILON
 from repro.core.interference import InterferenceModel
 from repro.core.online import OnlineConfig, OnlineMeasurement
 from repro.core.placement import DisciplineSpec, PlacementLayer
-from repro.core.policy import Mode
+from repro.core.policy import QUEUED_MODES, Mode
 from repro.core.profiler import ProfiledData
 from repro.core.task import KernelRequest, TaskKey
 
@@ -71,13 +76,50 @@ class ExecRecord:
     dispatched: float = 0.0
     booked: float = 0.0
     released: float = 0.0
+    ahead: int = 0
 
     def span(self) -> tuple:
         req = self.req
         return (spans.SEGMENT, req.task_instance, req.seq_index,
                 req.task_key.process, req.priority, self.device,
                 self.filler, req.submit_time, self.launch, self.start,
-                self.dispatched, self.end, self.booked, self.released)
+                self.dispatched, self.end, self.booked, self.released,
+                self.ahead)
+
+
+class DeviceQueue(queue.Queue):
+    """One device thread's queue of launched requests.
+
+    Items are ``(req, fut, filler, t_launch, order)``; ``order`` is the
+    request's ``(task arrival, task instance, seq_index)`` where the
+    engine lets it be reordered, else None. ``get`` returns ``(req, fut,
+    filler, t_launch, ahead)``, or None for the stop sentinel.
+
+    The leading run of orderable requests that share the head's priority
+    is served earliest task first (the holder election's order), and
+    ``ahead`` counts the run's requests taken over. A request of another
+    priority, a filler or any unorderable request ends the run, so it
+    keeps its place, and the thread never waits while the queue holds
+    work."""
+
+    def _get(self):
+        q = self.queue
+        head = q[0]
+        if head is None:
+            return q.popleft()
+        pick, key = 0, head[4]
+        if key is not None:
+            prio = head[0].priority
+            for i in range(1, len(q)):
+                item = q[i]
+                if (item is None or item[4] is None
+                        or item[0].priority != prio):
+                    break
+                if item[4] < key:
+                    pick, key = i, item[4]
+        item = q[pick]
+        del q[pick]
+        return item[:4] + (pick,)
 
 
 class JobCancelled(RuntimeError):
@@ -156,8 +198,12 @@ class WallClockEngine:
                                                   in range(devices)])
         # single-device alias kept for callers that inspect decision state
         self.policy = self.placement.policies[0]
-        self._device_qs: List["queue.Queue"] = [queue.Queue()
-                                               for _ in range(devices)]
+        self._device_qs: List[DeviceQueue] = [DeviceQueue()
+                                              for _ in range(devices)]
+        # equal-priority requests run earliest task first (see
+        # DeviceQueue); SHARING is the paper's plain-sharing baseline and
+        # EXCLUSIVE already serializes tasks
+        self._ordered = mode in QUEUED_MODES
         self._records: List[ExecRecord] = []
         self._futures: Dict[int, Future] = {}      # req.uid -> Future
         self._done_cbs: Dict[int, object] = {}     # req.uid -> on_complete
@@ -224,7 +270,7 @@ class WallClockEngine:
             item = dq.get()
             if item is None or self._stop:
                 break
-            req, fut, filler, t_launch = item
+            req, fut, filler, t_launch, ahead = item
             t0 = time.perf_counter()
             tm = spans.annotation("fikit/segment")
             out = err = None
@@ -239,7 +285,7 @@ class WallClockEngine:
             # a kept record pins no segment input on the device
             req.payload = None
             rec = ExecRecord(req, t0, t1, filler, device, t_launch,
-                             spans.dispatched_since(t0, t1))
+                             spans.dispatched_since(t0, t1), ahead=ahead)
             # the phases are annotated only inside an annotated segment
             book = tm and spans.annotation("fikit/segment/book")
             with self._lock:
@@ -395,7 +441,15 @@ class WallClockEngine:
         fut = self._futures.get(req.uid)
         if fut is None:                            # pragma: no cover
             fut = self._futures[req.uid] = Future()
-        self._device_qs[device].put((req, fut, filler, time.perf_counter()))
+        order = None
+        if self._ordered and not filler:
+            policy = self.placement.policies[device]
+            at = policy.active.get(req.task_instance)
+            if (at is not None and policy.queues.discipline_of(
+                    req.priority) == "fifo"):
+                order = (at.arrival, at.instance, req.seq_index)
+        self._device_qs[device].put((req, fut, filler, time.perf_counter(),
+                                     order))
 
     def device_of(self, instance: int) -> int:
         """The device a live task runs on, or -1."""

@@ -22,7 +22,9 @@ Every span starts with its kind. The spans of one request join on
   returned), ``booked`` (the scheduler's completion bookkeeping done and
   the engine lock released) and ``released`` (the completion callback
   returned). The phases dispatch, sync, book and callback add up to
-  ``released - start``.
+  ``released - start``. ``ahead`` is the number of queued
+  equal-priority requests, launched earlier, that the device queue took
+  this segment ahead of (0 where it took the queue's head).
 - ``host_work``: the segment's host post-processing (the head's sampling),
   run by the client inside the completion callback or its own loop.
 - ``gap``: a predicted idle gap the scheduler opened after a holder
@@ -76,6 +78,7 @@ class Segment(NamedTuple):
     end: float
     booked: float
     released: float
+    ahead: int
 
 
 class HostWork(NamedTuple):

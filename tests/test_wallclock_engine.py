@@ -171,3 +171,141 @@ def test_multi_device_threads_spread_and_steal():
         spans = sorted((r.start, r.end) for r in recs if r.device == d)
         for (s0, e0), (s1, e1) in zip(spans, spans[1:]):
             assert s1 >= e0 - 1e-9
+
+
+# ------------------------------------------------- equal-priority order
+def _equal_priority_tasks(mode, names=("a", "b"), n=4, **engine_kw):
+    """Tasks ``names``, all priority 0, run through ``run_async`` and begun
+    in that order. The first task's first segment holds the device until
+    every later task's first segment is queued behind it. Returns the
+    order segments ran in, as ``<task><seq>``, and the engine's records."""
+    order = []
+    started, go = threading.Event(), threading.Event()
+
+    def segments(name):
+        def seg(i):
+            def fn(state):
+                order.append(f"{name}{i}")
+                if name == names[0] and i == 0:
+                    started.set()
+                    go.wait(5)
+                time.sleep(0.001)
+                return state
+            return fn
+        return [Segment(f"{name}{i}", seg(i)) for i in range(n)]
+
+    errors, all_done = {}, threading.Event()
+
+    def on_done(name):
+        def done(result, jct, err):
+            errors[name] = err
+            if len(errors) == len(names):
+                all_done.set()
+        return done
+
+    with WallClockEngine(mode, **engine_kw) as eng:
+        clients = [HookClient(eng, TaskKey(nm), 0, segments(nm))
+                   for nm in names]
+        clients[0].run_async("x", on_done(names[0]))
+        assert started.wait(5)
+        for nm, cl in zip(names[1:], clients[1:]):
+            cl.run_async("x", on_done(nm))
+        go.set()
+        assert all_done.wait(5)
+    assert errors == {nm: None for nm in names}
+    return order, eng.records()
+
+
+def _runs_of(names, n):
+    return [f"{nm}{i}" for nm in names for i in range(n)]
+
+
+def _turns_of(names, n):
+    return [f"{nm}{i}" for i in range(n) for nm in names]
+
+
+@pytest.mark.parametrize("mode", [Mode.FIKIT, Mode.PREEMPT])
+def test_equal_priority_tasks_run_in_task_begin_order(mode):
+    """Once both are in flight, every remaining segment of the earlier
+    task runs before the later task's queued segment."""
+    order, _ = _equal_priority_tasks(mode)
+    assert order == _runs_of("ab", 4)
+
+
+def test_three_equal_priority_tasks_run_earliest_begun_first():
+    order, _ = _equal_priority_tasks(Mode.FIKIT, names="abc", n=3)
+    assert order == _runs_of("abc", 3)
+
+
+@pytest.mark.parametrize("mode,discipline", [
+    (Mode.SHARING, "fifo"), (Mode.FIKIT, "edf"), (Mode.FIKIT, "sjf")],
+    ids=["sharing", "edf", "sjf"])
+def test_equal_priority_tasks_keep_launch_order(mode, discipline):
+    """SHARING, and ``edf``/``sjf`` levels, serve the device queue in
+    launch order: two tasks in flight take one segment each in turn."""
+    order, _ = _equal_priority_tasks(mode, queue_discipline=discipline)
+    assert order == _turns_of("ab", 4)
+
+
+def test_lower_priority_request_queued_first_keeps_its_place():
+    """A queued lo request is not passed by a hi request launched after
+    it: the device queue reorders only among equal priorities."""
+    from repro.core.kernel_id import KernelID
+    from repro.core.task import KernelRequest
+
+    order = []
+    started, go = threading.Event(), threading.Event()
+
+    def payload(name, gate=False):
+        def call():
+            order.append(name)
+            if gate:
+                started.set()
+                go.wait(5)
+        return call
+
+    def req(key, prio, inst, seq, gate=False):
+        return KernelRequest(task_key=key,
+                             kernel_id=KernelID(f"{key.process}/k"),
+                             priority=prio, task_instance=inst,
+                             seq_index=seq,
+                             payload=payload(f"{key.process}{seq}", gate))
+
+    key_lo, key_hi = TaskKey("lo"), TaskKey("hi")
+    with WallClockEngine(Mode.FIKIT) as eng:
+        eng.task_begin(1, key_lo, 5)
+        futs = [eng.submit(req(key_lo, 5, 1, 0, gate=True)),
+                eng.submit(req(key_lo, 5, 1, 1))]
+        assert started.wait(5)
+        eng.task_begin(2, key_hi, 0)
+        futs.append(eng.submit(req(key_hi, 0, 2, 0)))
+        go.set()
+        for f in futs:
+            f.result(timeout=5)
+        eng.task_end(1)
+        eng.task_end(2)
+        recs = eng.records()
+    assert order == ["lo0", "lo1", "hi0"]
+    assert all(r.ahead == 0 for r in recs)
+
+
+@pytest.mark.parametrize("mode", [Mode.FIKIT, Mode.SHARING])
+def test_ahead_counts_the_requests_each_segment_was_taken_over(mode):
+    """In FIKIT mode each of a's segments after the first is taken ahead of
+    b's queued first segment (1), and b then runs alone (0). SHARING
+    never reorders. The span log carries the same field."""
+    from repro.core import spans
+
+    spans.clear()
+    _, recs = _equal_priority_tasks(mode)
+    got = {(r.req.task_key.process, r.req.seq_index): r.ahead for r in recs}
+    if mode is Mode.FIKIT:
+        want = {("a", i): int(i > 0) for i in range(4)}
+        want.update({("b", i): 0 for i in range(4)})
+    else:
+        want = {k: 0 for k in got}
+    assert got == want
+    logged = {(s.service, s.seq): s.ahead
+              for s in spans.read(spans.SEGMENT)}
+    assert logged == want
+    spans.clear()
