@@ -24,6 +24,15 @@ Three things the program does not give, the harness supplies from here:
   a one-item ``Carry`` list that the segment empties when it runs, so the
   kept payloads hold nothing on the device.
 
+What depends on the program family of a role's ``arch``, the harness
+takes from that family's module, ``benchlib/families/<family>.py``,
+found by name when the role is built (a family with no module is refused
+there): the weights' leaves and the program's parameter tree, and the
+reference's forward pass. The rest is shared by every family: drawing
+the weights from the seed (``weights``), the prompts (``traffic``), the
+timed window, and the gap a served token is judged by
+(``reference.token_gaps``).
+
 In a traced run (``annotate``), each segment runs inside a
 ``jax.profiler.TraceAnnotation`` named ``<role>/<program>`` so the trace
 can tell the two services apart; untraced runs carry no annotation.
@@ -37,8 +46,9 @@ import time
 import jax
 import numpy as np
 
-from benchlib import reference, traffic
-from benchlib.weights import make_weights, program_tree, seed32
+from benchlib import families, traffic
+from benchlib.reference import token_gaps
+from benchlib.weights import make_weights, seed32
 
 GRACE_S = 60.0
 TRACE_S = 3.0
@@ -83,14 +93,13 @@ class Role:
 
     def __init__(self, name: str, spec: dict, cls: dict, seed: int,
                  annotate: bool = False):
-        from repro.config import DENSE, get_config
+        from repro.config import get_config
         self.name = name
         self.m = dict(spec["model"])
         self.batch, self.seq = int(spec["batch"]), int(spec["seq"])
         self.cfg = get_config(spec["arch"]).replace(**self.m)
-        if self.cfg.family != DENSE:
-            raise ValueError(f"{spec['arch']}: the reference covers dense "
-                             f"decoders only")
+        #: the ``benchlib.families`` module of the program's family
+        self.family = families.find(self.cfg.family)
         self.annotate = annotate
         self.qos = cls["name"]
         self.cls = cls
@@ -104,8 +113,9 @@ class Role:
         from repro.models.segmentation import SegmentedService
         from repro.serving.engine import InferenceService
 
-        w = jax.block_until_ready(make_weights(self.m, seed))
-        svc = SegmentedService(self.cfg, program_tree(self.m, w),
+        w = jax.block_until_ready(make_weights(self.family, self.m, seed))
+        svc = SegmentedService(self.cfg,
+                               self.family.program_tree(self.m, w),
                                self.batch, self.seq)
         del w
         svc.make_input = self.feed.make_input
@@ -340,19 +350,19 @@ class Cell:
             pick = [rids[i] for i in sorted(rng.choice(
                 len(rids), size=min(len(rids), int(want[name])),
                 replace=False))] if rids else []
-            w = make_weights(role.m, self.seed)
+            w = make_weights(role.family, role.m, self.seed)
             widest = None
             for rid in pick:
                 toks = traffic.tokens(self.seed, name, rid[0], rid[1],
                                       role.m["vocab_size"], role.batch,
                                       role.seq)
-                ref = reference.served_logits(role.m, w, toks)
+                ref = role.family.served_logits(role.m, w, toks)
                 if precision == "float32":
                     served = role.results[rid][1]
                 else:
-                    served = reference.served_logits(
+                    served = role.family.served_logits(
                         role.m, w, toks, precision).argmax(-1)
-                g = float(reference.token_gaps(ref, served).max())
+                g = float(token_gaps(ref, served).max())
                 g = g if np.isfinite(g) else float("inf")
                 widest = g if widest is None else max(widest, g)
             del w

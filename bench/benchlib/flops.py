@@ -1,4 +1,5 @@
-"""Operations and bytes the dense decoder's algorithm needs, from shapes.
+"""Operations and bytes a model's algorithm needs, from shapes: what every
+program family shares.
 
 These count what the model requires, not what a compiled program happens
 to do, so they stay fixed while the program changes. Operations are the
@@ -6,10 +7,12 @@ matrix products (2 per multiply-add); norms, rotary embedding and the
 softmax are left out. Causal attention counts each query against the
 keys at or before it (inside the window, where one is set). Bytes are
 the least a call must move: its weights once, its input and its output.
+
+Each family counts its own layer programs (``families/<family>.py``:
+``layer_programs``) and names its head's count (``head_flops``); the
+counts of a request and a call's least time are shared here.
 """
 from __future__ import annotations
-
-from benchlib.weights import head_dim
 
 
 def _attended_keys(S: int, window) -> int:
@@ -20,33 +23,18 @@ def _attended_keys(S: int, window) -> int:
     return w * (w + 1) // 2 + (S - w) * w
 
 
-def layer_params(m: dict) -> int:
-    """Matrix parameters of one layer (the norm gains are left out)."""
-    D, H, Kh, F = m["d_model"], m["num_heads"], m["num_kv_heads"], m["d_ff"]
-    Dh = head_dim(m)
-    return D * H * Dh * 2 + D * Kh * Dh * 2 + 3 * D * F
-
-
-def layer_flops(m: dict, batch: int, seq: int) -> int:
-    H, Dh = m["num_heads"], head_dim(m)
-    pairs = _attended_keys(seq, m.get("sliding_window"))
-    return (2 * layer_params(m) * batch * seq
-            + 2 * 2 * batch * H * Dh * pairs)
-
-
-def layer_bytes(m: dict, batch: int, seq: int, itemsize: int = 2) -> int:
-    act = batch * seq * m["d_model"] * itemsize
-    return layer_params(m) * itemsize + 2 * act
-
-
 def head_flops(m: dict, batch: int, seq: int) -> int:
+    """An LM head over the whole vocabulary at every position."""
     return 2 * batch * seq * m["d_model"] * m["vocab_size"]
 
 
-def request_flops(m: dict, batch: int, seq: int) -> int:
-    """One request: every layer and the head (the embedding is a gather)."""
-    return (m["num_layers"] * layer_flops(m, batch, seq)
-            + head_flops(m, batch, seq))
+def request_flops(family, m: dict, batch: int, seq: int) -> int:
+    """One request of model ``m`` of program family ``family`` (its
+    ``benchlib.families`` module): every call of every layer program and
+    the head (the embedding is a gather)."""
+    layers = sum(calls * f for calls, f, _ in
+                 family.layer_programs(m, batch, seq).values())
+    return layers + family.head_flops(m, batch, seq)
 
 
 def least_time(flops: int, nbytes: int, peak: dict):

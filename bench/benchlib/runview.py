@@ -27,18 +27,20 @@ class RunView:
         self.peak = peak
         #: ``benchlib.trace.reduce`` of the traced sub-window, or None
         self.trace = trace
-        self._roles = {n: (r.m, r.batch, r.seq)
+        self._roles = {n: (r.family, r.m, r.batch, r.seq)
                        for n, r in cell.roles.items()}
 
     def request_flops(self, role: str) -> int:
         return flops.request_flops(*self._roles[role])
 
-    def least_time(self, role: str):
-        """Roofline time of one call of ``role``'s layer program, and the
-        bound ('compute' or 'memory') that sets it."""
-        m, b, s = self._roles[role]
-        return flops.least_time(flops.layer_flops(m, b, s),
-                                flops.layer_bytes(m, b, s), self.peak)
+    def least_time(self, role: str) -> dict:
+        """Per layer program of ``role``, by its program name: the
+        roofline time of one call, and the bound ('compute' or 'memory')
+        that sets it."""
+        family, m, b, s = self._roles[role]
+        return {p: flops.least_time(f, n, self.peak)
+                for p, (_calls, f, n) in
+                family.layer_programs(m, b, s).items()}
 
     def span(self, label: str):
         """Calls and device seconds of a traced span label, or None."""

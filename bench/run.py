@@ -169,9 +169,9 @@ def main(argv=None, root: Path = ROOT) -> int:
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         for role in ("hi", "lo"):
-            least, bound = view.least_time(role)
-            print(f"{role}/layer roofline: least {1e3 * least:.4f} ms per "
-                  f"call, {bound}-bound")
+            for program, (least, bound) in view.least_time(role).items():
+                print(f"{role}/{program} roofline: least {1e3 * least:.4f} "
+                      f"ms per call, {bound}-bound")
     else:
         e2e = end_to_end(win, cell, setup_s)
         for m in specs.metrics_for(bench, "end_to_end", args.workload):

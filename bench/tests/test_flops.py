@@ -5,6 +5,7 @@ import json
 import pytest
 
 from benchlib import flops
+from benchlib.families import dense
 from tiny import BENCH
 
 
@@ -41,16 +42,16 @@ CASES = [
 def test_layer_counts_match_hand_counts(config, role, params, layer_flops,
                                         layer_bytes):
     m, b, s = _model(config, role)
-    assert flops.layer_params(m) == params
-    assert flops.layer_flops(m, b, s) == layer_flops
-    assert flops.layer_bytes(m, b, s) == layer_bytes
+    assert dense.layer_params(m) == params
+    assert dense.layer_flops(m, b, s) == layer_flops
+    assert dense.layer_bytes(m, b, s) == layer_bytes
 
 
 def test_head_and_request_counts():
     m, b, s = _model("hi-qwen3-4b.lo-stablelm-1.6b", "hi")
     head = 2 * 512 * 2560 * 151936
-    assert flops.head_flops(m, b, s) == head
-    assert flops.request_flops(m, b, s) == 36 * flops.layer_flops(
+    assert dense.head_flops(m, b, s) == head
+    assert flops.request_flops(dense, m, b, s) == 36 * dense.layer_flops(
         m, b, s) + head
 
 

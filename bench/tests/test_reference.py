@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from benchlib import reference, weights
+from benchlib.families import dense
 
 ARCHS = ["qwen3-4b", "stablelm-1.6b", "h2o-danube-3-4b"]
 
@@ -27,12 +28,12 @@ def test_reference_matches_program_forward_in_float32(arch, tie):
     cfg = get_config(arch).reduced().replace(tie_embeddings=tie)
     assert cfg.dtype == "float32"
     m = _model_of(cfg)
-    w = weights.make_weights(m, seed=2 ** 31 + 17)
+    w = weights.make_weights(dense, m, seed=2 ** 31 + 17)
     tokens = np.random.default_rng(0).integers(0, cfg.vocab_size, (2, 96))
-    logits, _ = api.forward(weights.program_tree(m, w),
+    logits, _ = api.forward(dense.program_tree(m, w),
                             jnp.asarray(tokens, jnp.int32), cfg)
     want = np.asarray(logits, np.float32)[..., :reference.SERVED_VOCAB]
-    got = reference.served_logits(m, w, tokens)
+    got = dense.served_logits(m, w, tokens)
     assert np.abs(got - want).max() <= 1e-4 * np.abs(want).max()
     assert (reference.token_gaps(got, want.argmax(-1)) == 0).all()
 
@@ -44,7 +45,7 @@ def test_program_tree_has_the_programs_shapes():
     for arch, tie in [(a, False) for a in ARCHS] + [("qwen3-4b", True)]:
         cfg = get_config(arch).reduced().replace(tie_embeddings=tie)
         m = _model_of(cfg)
-        tree = weights.program_tree(m, weights.make_weights(m, 3))
+        tree = dense.program_tree(m, weights.make_weights(dense, m, 3))
         want = api.build_params(cfg, None)
         shapes = jax.tree.map(lambda a: (a.shape, str(a.dtype)), tree)
         assert shapes == jax.tree.map(lambda a: (a.shape, str(a.dtype)),
@@ -54,9 +55,9 @@ def test_program_tree_has_the_programs_shapes():
 def test_weights_depend_on_the_whole_seed():
     m = _model_of(__import__("repro.config", fromlist=["get_config"])
                   .get_config("stablelm-1.6b").reduced())
-    a = weights.make_weights(m, 5)["wq"]
-    b = weights.make_weights(m, 5 + 2 ** 32)["wq"]
-    c = weights.make_weights(m, 5)["wq"]
+    a = weights.make_weights(dense, m, 5)["wq"]
+    b = weights.make_weights(dense, m, 5 + 2 ** 32)["wq"]
+    c = weights.make_weights(dense, m, 5)["wq"]
     assert not np.array_equal(np.asarray(a), np.asarray(b))
     assert np.array_equal(np.asarray(a), np.asarray(c))
 
